@@ -68,6 +68,16 @@ def _tile_mask(iq, jk, bq: int, bk: int, *, causal: bool, window: int,
     return mask
 
 
+def _col(row_ref):
+    """(1, bq) row block of a per-query statistic -> (bq, 1) column.
+
+    lse and delta travel as lane-major rows of a (B*H, 1, L) array: the
+    TPU tiling rule wants a block's last two dims to be (8, 128)-multiples
+    or whole, and ``(1, bq)`` of a ``(1, L)`` plane is the lane-dense way
+    to meet it without a 128x lane-broadcast copy in HBM."""
+    return jnp.expand_dims(row_ref[0], -1)
+
+
 def _tile_live(iq, jk, bq: int, bk: int, *, causal: bool, window: int,
                q_off=0, k_off=0):
     """False iff the (iq, jk) tile is *entirely* masked, so its MXU work
@@ -170,11 +180,11 @@ def _dq_kernel(*refs, bq: int, bk: int, nk: int, causal: bool,
         mask = _tile_mask(iq, jk, bq, bk, causal=causal, window=window,
                           lreal=lreal, q_off=qo, k_off=ko)
         s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[...].reshape(bq, 1))             # (bq, bk)
+        p = jnp.exp(s - _col(lse_ref))                           # (bq, bk)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )                                                        # (bq, bk)
-        ds = p * (dp - delta_ref[...].reshape(bq, 1)) * scale
+        ds = p * (dp - _col(delta_ref)) * scale
         acc_ref[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -220,14 +230,14 @@ def _dkv_kernel(*refs, bq: int, bk: int, nq: int, G: int,
         mask = _tile_mask(iq, jk, bq, bk, causal=causal, window=window,
                           lreal=lreal, q_off=qo, k_off=ko)
         s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[...].reshape(bq, 1))         # (bq, bk)
+        p = jnp.exp(s - _col(lse_ref))                       # (bq, bk)
         dv_acc[...] += jax.lax.dot_general(                  # pᵀ dO -> (bk, dh)
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ds = p * (dp - delta_ref[...].reshape(bq, 1)) * scale
+        ds = p * (dp - _col(delta_ref)) * scale
         dk_acc[...] += jax.lax.dot_general(                  # dsᵀ q -> (bk, dh)
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -293,11 +303,11 @@ def _fwd_impl(q, k, v, causal, window, bq, bk, interpret, offs=None):
     ]
     out_specs = [
         pl.BlockSpec((1, bq, dhp), lambda bh, iq, jk, *_: (bh, iq, 0)),
-        pl.BlockSpec((1, bq), lambda bh, iq, jk, *_: (bh, iq)),
+        pl.BlockSpec((None, 1, bq), lambda bh, iq, jk, *_: (bh, 0, iq)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((B * H, Lqp, dhp), q.dtype),
-        jax.ShapeDtypeStruct((B * H, Lqp), jnp.float32),
+        jax.ShapeDtypeStruct((B * H, 1, Lqp), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((bq, 1), jnp.float32),
@@ -308,6 +318,7 @@ def _fwd_impl(q, k, v, causal, window, bq, bk, interpret, offs=None):
         out, lse = pl.pallas_call(
             kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, scratch_shapes=scratch, interpret=interpret,
+            name="flash_fwd",
         )(qr, kr, vr)
     else:
         out, lse = pl.pallas_call(
@@ -315,7 +326,7 @@ def _fwd_impl(q, k, v, causal, window, bq, bk, interpret, offs=None):
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=scratch),
-            out_shape=out_shape, interpret=interpret,
+            out_shape=out_shape, interpret=interpret, name="flash_fwd",
         )(jnp.asarray(offs, jnp.int32), qr, kr, vr)
     out = out.reshape(B, H, Lqp, dhp).transpose(0, 2, 1, 3)[:, :L, :, :dh]
     lse = lse.reshape(B, H, Lqp)[:, :, :L]
@@ -341,8 +352,9 @@ def _bwd_impl(q, k, v, o, lse, do, causal, window, bq, bk, interpret,
     # delta = rowsum(dO ⊙ O): the softmax-normalization term of dS. Padded
     # rows carry dO = 0, so lse/delta = 0 there is inert by construction.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = jnp.pad(delta.transpose(0, 2, 1).reshape(B * H, L), ((0, 0), (0, pq)))
-    lser = jnp.pad(lse.reshape(B * H, L), ((0, 0), (0, pq)))
+    delta = jnp.pad(delta.transpose(0, 2, 1).reshape(B * H, 1, L),
+                    ((0, 0), (0, 0), (0, pq)))
+    lser = jnp.pad(lse.reshape(B * H, 1, L), ((0, 0), (0, 0), (0, pq)))
 
     nq, nk = Lqp // bq, Lkp // bk
     offset = offs is not None
@@ -361,8 +373,8 @@ def _bwd_impl(q, k, v, o, lse, do, causal, window, bq, bk, interpret,
         pl.BlockSpec((1, bk, dhp), kv_index_q),
         pl.BlockSpec((1, bk, dhp), kv_index_q),
         pl.BlockSpec((1, bq, dhp), lambda bh, iq, jk, *_: (bh, iq, 0)),
-        pl.BlockSpec((1, bq), lambda bh, iq, jk, *_: (bh, iq)),
-        pl.BlockSpec((1, bq), lambda bh, iq, jk, *_: (bh, iq)),
+        pl.BlockSpec((None, 1, bq), lambda bh, iq, jk, *_: (bh, 0, iq)),
+        pl.BlockSpec((None, 1, bq), lambda bh, iq, jk, *_: (bh, 0, iq)),
     ]
     dq_out_specs = pl.BlockSpec((1, bq, dhp), lambda bh, iq, jk, *_: (bh, iq, 0))
     dq_out_shape = jax.ShapeDtypeStruct((B * H, Lqp, dhp), q.dtype)
@@ -373,13 +385,13 @@ def _bwd_impl(q, k, v, o, lse, do, causal, window, bq, bk, interpret,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=dq_grid, in_specs=dq_in_specs,
                 out_specs=dq_out_specs, scratch_shapes=dq_scratch),
-            out_shape=dq_out_shape, interpret=interpret,
+            out_shape=dq_out_shape, interpret=interpret, name="flash_dq",
         )(offs, qr, kr, vr, dor, lser, delta)
     else:
         dq = pl.pallas_call(
             dq_kern, grid=dq_grid, in_specs=dq_in_specs,
             out_specs=dq_out_specs, out_shape=dq_out_shape,
-            scratch_shapes=dq_scratch, interpret=interpret,
+            scratch_shapes=dq_scratch, interpret=interpret, name="flash_dq",
         )(qr, kr, vr, dor, lser, delta)
 
     # kv-major grid; the two inner dims (g, iq) sweep the query stream of
@@ -388,7 +400,7 @@ def _bwd_impl(q, k, v, o, lse, do, causal, window, bq, bk, interpret,
         return ((bkv // KV) * H + (bkv % KV) * G + g, iq, 0)
 
     def qrow_index(bkv, jk, g, iq, *_):
-        return ((bkv // KV) * H + (bkv % KV) * G + g, iq)
+        return ((bkv // KV) * H + (bkv % KV) * G + g, 0, iq)
 
     def kv_index(bkv, jk, g, iq, *_):
         return (bkv, jk, 0)
@@ -402,8 +414,8 @@ def _bwd_impl(q, k, v, o, lse, do, causal, window, bq, bk, interpret,
         pl.BlockSpec((1, bk, dhp), kv_index),
         pl.BlockSpec((1, bk, dhp), kv_index),
         pl.BlockSpec((1, bq, dhp), q_index),
-        pl.BlockSpec((1, bq), qrow_index),
-        pl.BlockSpec((1, bq), qrow_index),
+        pl.BlockSpec((None, 1, bq), qrow_index),
+        pl.BlockSpec((None, 1, bq), qrow_index),
     ]
     dkv_out_specs = [
         pl.BlockSpec((1, bk, dhp), kv_index),
@@ -423,13 +435,14 @@ def _bwd_impl(q, k, v, o, lse, do, causal, window, bq, bk, interpret,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=dkv_grid, in_specs=dkv_in_specs,
                 out_specs=dkv_out_specs, scratch_shapes=dkv_scratch),
-            out_shape=dkv_out_shape, interpret=interpret,
+            out_shape=dkv_out_shape, interpret=interpret, name="flash_dkv",
         )(offs, qr, kr, vr, dor, lser, delta)
     else:
         dk, dv = pl.pallas_call(
             dkv_kern, grid=dkv_grid, in_specs=dkv_in_specs,
             out_specs=dkv_out_specs, out_shape=dkv_out_shape,
             scratch_shapes=dkv_scratch, interpret=interpret,
+            name="flash_dkv",
         )(qr, kr, vr, dor, lser, delta)
 
     def unfold(x, N, Lp):

@@ -100,7 +100,7 @@ def dequantize_kv(q, scale, dh: int):
 
 
 def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, spos_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, nk: int, causal: bool,
+                   m_ref, l_ref, acc_ref, *, nk: int, kv: int, causal: bool,
                    window: int, scale: float):
     jk = pl.program_id(1)
 
@@ -117,7 +117,7 @@ def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, spos_ref, o_ref,
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                  # (G, bk)
 
-    qpos = qpos_ref[0, 0]                      # scalar absolute query position
+    qpos = qpos_ref[pl.program_id(0) // kv]    # scalar absolute query position
     spos = spos_ref[...]                       # (1, bk) absolute slot positions
     mask = spos >= 0                           # empty / padded slots
     if causal:
@@ -166,31 +166,36 @@ def flash_decode_kernel(q, k, v, q_pos, slot_pos, *, causal: bool = True,
     vr = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, pdh)))
     kr = kr.transpose(0, 2, 1, 3).reshape(B * KV, Sp, dhp)
     vr = vr.transpose(0, 2, 1, 3).reshape(B * KV, Sp, dhp)
-    sposr = jnp.pad(slot_pos, ((0, 0), (0, pk)), constant_values=-1)
-    qposr = q_pos.reshape(B, 1).astype(jnp.int32)
+    # slot positions as (B, 1, Sp) so a (1, bk) block is whole in its
+    # sublane dim (TPU tiling); the query position is an SMEM scalar.
+    sposr = jnp.pad(slot_pos, ((0, 0), (0, pk)), constant_values=-1)[:, None]
+    qposr = q_pos.reshape(B).astype(jnp.int32)
 
     nk = Sp // bk
     grid = (B * KV, nk)
 
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, nk=nk, causal=causal,
+        functools.partial(_decode_kernel, nk=nk, kv=KV, causal=causal,
                           window=window, scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, jk: (bh // KV, 0)),
-            pl.BlockSpec((1, G, dhp), lambda bh, jk: (bh, 0, 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bh, jk: (bh, jk, 0)),
-            pl.BlockSpec((1, bk, dhp), lambda bh, jk: (bh, jk, 0)),
-            pl.BlockSpec((1, bk), lambda bh, jk: (bh // KV, jk)),
-        ],
-        out_specs=pl.BlockSpec((1, G, dhp), lambda bh, jk: (bh, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, G, dhp), lambda bh, jk, _: (bh, 0, 0)),
+                pl.BlockSpec((1, bk, dhp), lambda bh, jk, _: (bh, jk, 0)),
+                pl.BlockSpec((1, bk, dhp), lambda bh, jk, _: (bh, jk, 0)),
+                pl.BlockSpec((None, 1, bk),
+                             lambda bh, jk, _: (bh // KV, 0, jk)),
+            ],
+            out_specs=pl.BlockSpec((1, G, dhp), lambda bh, jk, _: (bh, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, dhp), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B * KV, G, dhp), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, dhp), jnp.float32),
-        ],
-        interpret=interpret,
+        interpret=interpret, name="dense_decode",
     )(qposr, qr, kr, vr, sposr)
     return out.reshape(B, KV, G, dhp)[..., :dh].reshape(B, 1, H, dh)
 
@@ -231,6 +236,21 @@ def flash_decode_ref(q, k, v, q_pos, slot_pos, *, causal: bool = True,
     return out.reshape(B, Lq, H, dh).astype(q.dtype)
 
 
+def _row_positions(qpos_ref, b, lq: int, g: int):
+    """Absolute position of each score row ``l*g + h`` (query ``l`` of
+    sequence ``b``), read from the (B, lq) SMEM scalar table — a scalar
+    for the single-query step, an (lq*g, 1) column for speculative verify.
+    Built from scalars because a (1, lq) VMEM block would break the TPU
+    tiling rule and its transpose to a column does not lower."""
+    qpos = qpos_ref[b, lq - 1]
+    if lq == 1:
+        return qpos
+    row = jax.lax.broadcasted_iota(jnp.int32, (lq * g, 1), 0)
+    for l in range(lq - 2, -1, -1):
+        qpos = jnp.where(row < (l + 1) * g, qpos_ref[b, l], qpos)
+    return qpos
+
+
 def _paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, ppos_ref,
                          o_ref, m_ref, l_ref, acc_ref, *, nb: int, kv: int,
                          lq: int, causal: bool, window: int, scale: float):
@@ -260,9 +280,7 @@ def _paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, ppos_ref,
 
         # per-row query position: row l*G + g is query l (speculative
         # verify feeds lq > 1 drafted tokens at ascending positions)
-        g = q_ref.shape[1] // lq
-        qpos = qpos_ref[...].reshape(lq, 1)     # (lq, 1)
-        qpos = jnp.broadcast_to(qpos, (lq, g)).reshape(lq * g, 1)
+        qpos = _row_positions(qpos_ref, bh // kv, lq, q_ref.shape[1] // lq)
         spos = ppos_ref[...]                    # (1, psp) absolute positions
         mask = spos >= 0
         if causal:
@@ -303,9 +321,10 @@ def flash_paged_decode_kernel(q, k_pages, v_pages, q_pos, block_table,
     Lq > 1 is the speculative-verify path: the Lq drafted queries fold
     into the kernel's row (sublane) dim next to the G grouped heads —
     (B*KV, Lq*G, dhp) — so one grid walk over the pages scores every
-    draft at once, with per-row positions rebuilt in VMEM from the
-    (1, Lq) qpos block. The grid and page gathers are identical to the
-    Lq=1 step; only the row count of the score tile grows.
+    draft at once, with per-row positions rebuilt from the (B, Lq) qpos
+    table in SMEM (:func:`_row_positions`). The grid and page gathers are
+    identical to the Lq=1 step; only the row count of the score tile
+    grows.
 
     The kv tile size IS the page size, so pick page_size >= the dtype's
     sublane granule (8 for f32, 16 for bf16) on real TPUs; smaller pages
@@ -329,35 +348,34 @@ def flash_paged_decode_kernel(q, k_pages, v_pages, q_pos, block_table,
                  ).transpose(2, 0, 1, 3)        # (KV, n_pages, psp, dhp)
     vt = jnp.pad(v_pages, ((0, 0), (0, pps), (0, 0), (0, pdh))
                  ).transpose(2, 0, 1, 3)
-    pposr = jnp.pad(page_pos, ((0, 0), (0, pps)), constant_values=-1)
+    pposr = _page_pos_rows(page_pos, pps)
     qposr = jnp.broadcast_to(q_pos.reshape(B, -1), (B, Lq)).astype(jnp.int32)
     bt = block_table.astype(jnp.int32)
 
-    def page_of(bh, jk, bt_ref):
+    def page_of(bh, jk, bt_ref, *_):
         return jnp.maximum(bt_ref[bh // KV, jk], 0)
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, nb=nb, kv=KV, lq=Lq,
                           causal=causal, window=window, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B * KV, nb),
             in_specs=[
-                pl.BlockSpec((1, Lq), lambda bh, jk, bt_ref: (bh // KV, 0)),
                 pl.BlockSpec((1, Lq * G, dhp),
-                             lambda bh, jk, bt_ref: (bh, 0, 0)),
+                             lambda bh, jk, *_: (bh, 0, 0)),
                 pl.BlockSpec((1, 1, psp, dhp),
-                             lambda bh, jk, bt_ref:
-                             (bh % KV, page_of(bh, jk, bt_ref), 0, 0)),
+                             lambda bh, jk, *refs:
+                             (bh % KV, page_of(bh, jk, *refs), 0, 0)),
                 pl.BlockSpec((1, 1, psp, dhp),
-                             lambda bh, jk, bt_ref:
-                             (bh % KV, page_of(bh, jk, bt_ref), 0, 0)),
-                pl.BlockSpec((1, psp),
-                             lambda bh, jk, bt_ref:
-                             (page_of(bh, jk, bt_ref), 0)),
+                             lambda bh, jk, *refs:
+                             (bh % KV, page_of(bh, jk, *refs), 0, 0)),
+                pl.BlockSpec((None, 1, psp),
+                             lambda bh, jk, *refs:
+                             (page_of(bh, jk, *refs), 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, Lq * G, dhp),
-                                   lambda bh, jk, bt_ref: (bh, 0, 0)),
+                                   lambda bh, jk, *_: (bh, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((Lq * G, 1), jnp.float32),
                 pltpu.VMEM((Lq * G, 1), jnp.float32),
@@ -365,10 +383,16 @@ def flash_paged_decode_kernel(q, k_pages, v_pages, q_pos, block_table,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * KV, Lq * G, dhp), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_decode",
     )(bt, qposr, qr, kt, vt, pposr)
     out = out.reshape(B, KV, Lq, G, dhp)[..., :dh]
     return out.transpose(0, 2, 1, 3, 4).reshape(B, Lq, H, dh)
+
+
+def _page_pos_rows(page_pos, pps: int):
+    """(n_pages, ps) -> (n_pages, 1, ps + pps), pad slots at -1: one page's
+    positions are then a (1, psp) block that is whole in both tiled dims."""
+    return jnp.pad(page_pos, ((0, 0), (0, pps)), constant_values=-1)[:, None]
 
 
 def flash_paged_decode_ref(q, k_pages, v_pages, q_pos, block_table, page_pos,
@@ -412,24 +436,37 @@ def flash_paged_decode(q, k_pages, v_pages, q_pos, block_table, page_pos, *,
 
 
 def _dequant_tile(qt, sc, bits: int, group: int):
-    """Dequantize one kv tile in VMEM: (psp, dhq_padded) int8 pages +
-    (psp, sgr) f32 scales -> (psp, W) f32. int4 tiles unpack two nibbles
-    per byte first (zero pad bytes unpack to zero rows, which the
-    page_pos mask already excludes). ``sgr == 1`` is the per-token fast
-    path (one broadcast multiply); grouped scales reshape the padded tile
-    into (psp, sgr, group) — alignment holds because the group width is a
-    power of two and the lane padding is a multiple of 128."""
+    """Dequantize one kv tile in VMEM: (psp, dhqp) int8 pages + (psp, ngr)
+    f32 scales -> (psp, W) f32.
+
+    int4 tiles unpack to ``[low nibbles | high nibbles]``, i.e. the even
+    head dims in lanes [0, dhqp) and the odd ones in [dhqp, 2*dhqp): two
+    lane-aligned halves joined by a concat, because interleaving them back
+    into dim order is a shape cast Mosaic does not lower. The wrapper
+    permutes q to that order and the output back. Grouped scales expand to
+    the lane layout by one select per group (a lane-splitting reshape does
+    not lower either); pad lanes hold zeros and need no scale.
+    """
+    x = qt.astype(jnp.int32)
     if bits == 4:
-        lo = jnp.right_shift(jnp.left_shift(qt, 4), 4)
-        hi = jnp.right_shift(qt, 4)
-        qt = jnp.stack([lo, hi], axis=-1).reshape(qt.shape[0],
-                                                  qt.shape[1] * 2)
-    x = qt.astype(jnp.float32)
-    if sc.shape[-1] == 1:
+        x = jnp.concatenate([jnp.right_shift(jnp.left_shift(x, 28), 28),
+                             jnp.right_shift(x, 4)], axis=-1)
+    x = x.astype(jnp.float32)
+    ngr = sc.shape[-1]
+    if ngr == 1:
         return x * sc
-    psp, W = x.shape
-    return (x.reshape(psp, sc.shape[-1], group) * sc[:, :, None]
-            ).reshape(psp, W)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[-1]), 1)
+    if bits == 4:
+        half = qt.shape[-1]
+        odd = (lane >= half).astype(jnp.int32)
+        dim = 2 * (lane - odd * half) + odd
+    else:
+        dim = lane
+    full = jnp.zeros_like(x)
+    for j in range(ngr):
+        in_group = (dim >= j * group) & (dim < (j + 1) * group)
+        full = jnp.where(in_group, sc[:, j:j + 1], full)
+    return x * full
 
 
 def _quant_paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref,
@@ -457,9 +494,7 @@ def _quant_paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref,
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                          # (lq*G, psp)
 
-        g = q_ref.shape[1] // lq
-        qpos = qpos_ref[...].reshape(lq, 1)                # per-query rows
-        qpos = jnp.broadcast_to(qpos, (lq, g)).reshape(lq * g, 1)
+        qpos = _row_positions(qpos_ref, bh // kv, lq, q_ref.shape[1] // lq)
         spos = ppos_ref[...]                               # (1, psp)
         mask = spos >= 0
         if causal:
@@ -515,28 +550,28 @@ def flash_paged_decode_quant_kernel(q, k_pages, v_pages, k_scale, v_scale,
     W = dhqp if bits == 8 else 2 * dhqp   # dequantized tile width
     pps = (-ps) % 8
     psp = ps + pps
-    # sgr: scale lanes after padding. Per-token scales broadcast over the
-    # whole row; grouped scales pad with zero groups so the reshape in
-    # _dequant_tile stays group-aligned over the padded width.
-    sgr = 1 if ngr == 1 else W // group
-    assert sgr == 1 or (W % group == 0 and sgr >= ngr), (W, group, ngr)
 
-    qr = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, W - dh)))
+    if bits == 4:   # q in the tile's [even dims | odd dims] lane order
+        q = jnp.concatenate(
+            [jnp.pad(q[..., 0::2], ((0, 0),) * 3 + ((0, dhqp - dhq),)),
+             jnp.pad(q[..., 1::2], ((0, 0),) * 3 + ((0, dhqp - dhq),))],
+            axis=-1)
+    qr = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, W - q.shape[-1])))
     qr = qr.reshape(B, Lq, KV, G, W).transpose(0, 2, 1, 3, 4)
     qr = qr.reshape(B * KV, Lq * G, W)
     kt = jnp.pad(k_pages, ((0, 0), (0, pps), (0, 0), (0, dhqp - dhq))
                  ).transpose(2, 0, 1, 3)        # (KV, n_pages, psp, dhqp)
     vt = jnp.pad(v_pages, ((0, 0), (0, pps), (0, 0), (0, dhqp - dhq))
                  ).transpose(2, 0, 1, 3)
-    kst = jnp.pad(k_scale, ((0, 0), (0, pps), (0, 0), (0, sgr - ngr))
-                  ).transpose(2, 0, 1, 3)       # (KV, n_pages, psp, sgr)
-    vst = jnp.pad(v_scale, ((0, 0), (0, pps), (0, 0), (0, sgr - ngr))
+    kst = jnp.pad(k_scale, ((0, 0), (0, pps), (0, 0), (0, 0))
+                  ).transpose(2, 0, 1, 3)       # (KV, n_pages, psp, ngr)
+    vst = jnp.pad(v_scale, ((0, 0), (0, pps), (0, 0), (0, 0))
                   ).transpose(2, 0, 1, 3)
-    pposr = jnp.pad(page_pos, ((0, 0), (0, pps)), constant_values=-1)
+    pposr = _page_pos_rows(page_pos, pps)
     qposr = jnp.broadcast_to(q_pos.reshape(B, -1), (B, Lq)).astype(jnp.int32)
     bt = block_table.astype(jnp.int32)
 
-    def page_of(bh, jk, bt_ref):
+    def page_of(bh, jk, bt_ref, *_):
         return jnp.maximum(bt_ref[bh // KV, jk], 0)
 
     out = pl.pallas_call(
@@ -544,30 +579,29 @@ def flash_paged_decode_quant_kernel(q, k_pages, v_pages, k_scale, v_scale,
                           causal=causal, window=window, scale=scale,
                           bits=bits, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B * KV, nb),
             in_specs=[
-                pl.BlockSpec((1, Lq), lambda bh, jk, bt_ref: (bh // KV, 0)),
                 pl.BlockSpec((1, Lq * G, W),
-                             lambda bh, jk, bt_ref: (bh, 0, 0)),
+                             lambda bh, jk, *_: (bh, 0, 0)),
                 pl.BlockSpec((1, 1, psp, dhqp),
-                             lambda bh, jk, bt_ref:
-                             (bh % KV, page_of(bh, jk, bt_ref), 0, 0)),
+                             lambda bh, jk, *refs:
+                             (bh % KV, page_of(bh, jk, *refs), 0, 0)),
                 pl.BlockSpec((1, 1, psp, dhqp),
-                             lambda bh, jk, bt_ref:
-                             (bh % KV, page_of(bh, jk, bt_ref), 0, 0)),
-                pl.BlockSpec((1, 1, psp, sgr),
-                             lambda bh, jk, bt_ref:
-                             (bh % KV, page_of(bh, jk, bt_ref), 0, 0)),
-                pl.BlockSpec((1, 1, psp, sgr),
-                             lambda bh, jk, bt_ref:
-                             (bh % KV, page_of(bh, jk, bt_ref), 0, 0)),
-                pl.BlockSpec((1, psp),
-                             lambda bh, jk, bt_ref:
-                             (page_of(bh, jk, bt_ref), 0)),
+                             lambda bh, jk, *refs:
+                             (bh % KV, page_of(bh, jk, *refs), 0, 0)),
+                pl.BlockSpec((1, 1, psp, ngr),
+                             lambda bh, jk, *refs:
+                             (bh % KV, page_of(bh, jk, *refs), 0, 0)),
+                pl.BlockSpec((1, 1, psp, ngr),
+                             lambda bh, jk, *refs:
+                             (bh % KV, page_of(bh, jk, *refs), 0, 0)),
+                pl.BlockSpec((None, 1, psp),
+                             lambda bh, jk, *refs:
+                             (page_of(bh, jk, *refs), 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, Lq * G, W),
-                                   lambda bh, jk, bt_ref: (bh, 0, 0)),
+                                   lambda bh, jk, *_: (bh, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((Lq * G, 1), jnp.float32),
                 pltpu.VMEM((Lq * G, 1), jnp.float32),
@@ -575,9 +609,12 @@ def flash_paged_decode_quant_kernel(q, k_pages, v_pages, k_scale, v_scale,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * KV, Lq * G, W), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_decode_quant",
     )(bt, qposr, qr, kt, vt, kst, vst, pposr)
-    out = out.reshape(B, KV, Lq, G, W)[..., :dh]
+    out = out.reshape(B, KV, Lq, G, W)
+    if bits == 4:
+        out = jnp.stack([out[..., :dhq], out[..., dhqp:dhqp + dhq]], axis=-1)
+    out = out.reshape(B, KV, Lq, G, -1)[..., :dh]
     return out.transpose(0, 2, 1, 3, 4).reshape(B, Lq, H, dh)
 
 

@@ -77,6 +77,6 @@ def segment_matmul(f, alpha, gz, k: int, *, bb: int = DEFAULT_BB,
         out_specs=pl.BlockSpec((K, bm), lambda jm, i: (0, jm)),
         out_shape=jax.ShapeDtypeStruct((K, m + pm), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, bm), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="pamm_apply",
     )(fp, ap, gzp)
     return out[:k, :m]

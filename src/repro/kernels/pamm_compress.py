@@ -7,7 +7,8 @@ index, and ||X_i|| — in ONE pass over X:
   grid = (b/bm, n/bn); each (i, j) step streams an (bm, bn) tile of X and a
   (k, bn) tile of C HBM->VMEM, accumulates partial dot products (bm, k) and
   squared norms (bm, 1) in f32 VMEM scratch (MXU for the dots), and on the
-  last n-tile runs the |csim| arg-max on the VPU and writes (cs, idx, norm).
+  last n-tile runs the |csim| arg-max on the VPU (masked lane reductions)
+  and writes (cs, idx, norm).
 
 TPU adaptation vs the paper's CUDA version (DESIGN.md §3): the csim matmul
 lands on the MXU systolic array; the argmax is a lane reduction (the paper
@@ -49,10 +50,17 @@ def _kernel(x_ref, c_ref, invnc_ref, cs_ref, idx_ref, norm_ref,
         norm_a = jnp.sqrt(sq_ref[...])           # (bm, 1)
         inv_na = 1.0 / jnp.maximum(norm_a, 1e-20)
         csim = acc_ref[...] * inv_na * invnc_ref[...]  # (bm, k)
-        best = jnp.argmax(jnp.abs(csim), axis=1)       # (bm,)
-        cs = jnp.take_along_axis(csim, best[:, None], axis=1)
-        cs_ref[...] = cs
-        idx_ref[...] = best[:, None].astype(jnp.int32)
+        # argmax and the winner's value as masked lane reductions: Mosaic
+        # does not lower an in-kernel gather (take_along_axis). Ties go to
+        # the lowest index, as jnp.argmax does.
+        mag = jnp.abs(csim)
+        lane = jax.lax.broadcasted_iota(jnp.int32, csim.shape, 1)
+        top = jnp.max(mag, axis=1, keepdims=True)
+        best = jnp.min(jnp.where(mag == top, lane, csim.shape[1]), axis=1,
+                       keepdims=True)                  # (bm, 1)
+        cs_ref[...] = jnp.sum(jnp.where(lane == best, csim, 0.0), axis=1,
+                              keepdims=True)
+        idx_ref[...] = best
         norm_ref[...] = norm_a
 
 
@@ -102,6 +110,6 @@ def csim_argmax(x, c, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
             pltpu.VMEM((bm, K), jnp.float32),
             pltpu.VMEM((bm, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="pamm_compress",
     )(xp, cp, invnc)
     return cs[:b, 0], jnp.minimum(idx[:b, 0], k - 1), norm[:b, 0]

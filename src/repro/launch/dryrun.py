@@ -195,9 +195,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *, verbose=True,
     param_sh = sh.sanitize_shardings(param_sh, shapes_tree, mesh)
 
     t0 = time.monotonic()
-    set_mesh = getattr(jax, "set_mesh", None)
-    mesh_ctx = set_mesh(mesh) if set_mesh is not None else mesh
-    with mesh_ctx:
+    with jax.set_mesh(mesh):
         if mode == "train":
             opt_init, _ = make_optimizer(rcfg.optimizer)
             opt_shapes = jax.eval_shape(opt_init, shapes_tree)

@@ -19,13 +19,10 @@ V5E_ICI_BW = 50e9             # bytes/s per link
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across JAX versions: ``axis_types`` (explicit-sharding
-    API) only exists on newer releases; older ones are Auto-only anyway."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto: sharding stays GSPMD-propagated
+    from the rules, not part of the array types (Explicit is the default)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.configs import RunConfig, get_config
 from repro.data import SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_model
 from repro.serve import Request, SamplingParams, ServeEngine
 
@@ -204,4 +205,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -20,8 +20,8 @@ import numpy as np
 
 from repro.configs import RunConfig, get_config
 from repro.data import SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
-from repro.models import param_specs
 from repro.runtime import sharding as sh
 from repro.runtime.fault import StragglerWatchdog, run_supervised
 from repro.train import (
@@ -30,6 +30,34 @@ from repro.train import (
     make_shard_map_train_step,
     make_train_step,
 )
+
+
+def build_trainer(cfg, rcfg, *, total_steps: int, executor: str = "jit",
+                  mesh=None):
+    """Initial TrainState, jitted ``step(state, batch, step_idx)`` and the
+    batch sharding (None: host batches go to the default device) for one
+    executor — the CLI below and ``chip_smoke.py`` both train through it.
+
+    ``shard_map`` needs ``mesh``; it returns state laid out with ZeRO-1
+    shardings and a step jitted with donated state. ``jit`` runs on the
+    default device, or GSPMD-shards the params over ``mesh`` when given.
+    """
+    key = jax.random.key(rcfg.seed)
+    if executor == "shard_map":
+        state, _ = init_distributed_state(cfg, rcfg, key, mesh)
+        step_fn = make_shard_map_train_step(
+            cfg, rcfg, total_steps=total_steps, mesh=mesh)
+        return (state, step_fn,
+                jax.sharding.NamedSharding(mesh, sh.data_pspec(mesh)))
+    state, specs = init_train_state(cfg, rcfg, key)
+    if mesh is not None:
+        param_sh = sh.spec_tree_to_shardings(specs, mesh)
+        state = state._replace(params=jax.device_put(state.params, param_sh))
+    # plan resolution sees the mesh: shard-local PAMM blocking
+    # (blocks=auto) and backend selection are derived there, not threaded
+    # as flags.
+    step_fn = make_train_step(cfg, rcfg, total_steps=total_steps, mesh=mesh)
+    return state, jax.jit(step_fn, donate_argnums=(0,)), None
 
 
 def main(argv=None):
@@ -96,39 +124,20 @@ def main(argv=None):
     stream = SyntheticStream.for_arch(cfg, args.seq_len, args.global_batch)
 
     mesh = None
-    batch_sharding = None
     if args.executor == "shard_map":
         # default mesh: all visible devices on the data axis (minus the
         # context degree when ring attention is requested)
         cp = max(1, args.mesh_context)
         dm = args.data_model or (max(1, len(jax.devices()) // cp), 1)
         mesh = make_debug_mesh(*dm, context=cp)
+        sh.validate_seq_divisible(args.seq_len, mesh, where="launch")
+    elif args.data_model:
+        mesh = make_debug_mesh(*args.data_model)
+    if mesh is not None:
         sh.validate_batch_divisible(args.global_batch, mesh,
                                     grad_accum=rcfg.grad_accum, where="launch")
-        sh.validate_seq_divisible(args.seq_len, mesh, where="launch")
-        state, specs = init_distributed_state(
-            cfg, rcfg, jax.random.key(rcfg.seed), mesh)
-        # already jitted with ZeRO-1 out_shardings + donated state
-        step_fn = make_shard_map_train_step(
-            cfg, rcfg, total_steps=args.steps, mesh=mesh)
-        batch_sharding = jax.sharding.NamedSharding(mesh, sh.data_pspec(mesh))
-    else:
-        state, specs = init_train_state(cfg, rcfg, jax.random.key(rcfg.seed))
-        if args.data_model:
-            mesh = make_debug_mesh(*args.data_model)
-            sh.validate_batch_divisible(args.global_batch, mesh,
-                                        grad_accum=rcfg.grad_accum,
-                                        where="launch")
-            param_sh = sh.spec_tree_to_shardings(specs, mesh)
-            state = state._replace(
-                params=jax.device_put(state.params, param_sh),
-                opt=state.opt,
-            )
-        # plan resolution sees the mesh: shard-local PAMM blocking
-        # (blocks=auto) and backend selection are derived here, not
-        # threaded as flags.
-        step_fn = make_train_step(cfg, rcfg, total_steps=args.steps, mesh=mesh)
-        step_fn = jax.jit(step_fn, donate_argnums=(0,))
+    state, step_fn, batch_sharding = build_trainer(
+        cfg, rcfg, total_steps=args.steps, executor=args.executor, mesh=mesh)
 
     holder = {"state": state, "metrics": None}
     watchdog = StragglerWatchdog()
@@ -167,4 +176,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

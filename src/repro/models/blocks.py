@@ -323,9 +323,7 @@ def _rev_stage_primal(cfg, rcfg, unit, si, resolved, positions):
         return (x1h, x1l, x2h, x2l, aux, tele), None
 
     def primal(unit_params, x1h, x1l, x2h, x2l, aux, tele, key_data):
-        from repro.runtime.sharding import scan_compat
-
-        (x1h, x1l, x2h, x2l, aux, tele), _ = scan_compat(
+        (x1h, x1l, x2h, x2l, aux, tele), _ = jax.lax.scan(
             body, (x1h, x1l, x2h, x2l, aux, tele), (unit_params, key_data))
         return x1h, x1l, x2h, x2l, aux, tele
 
@@ -374,8 +372,6 @@ def reversible_stage(cfg, rcfg, unit, si, resolved, unit_params,
         return out, (unit_params, y1h, y1l, y2h, y2l, key_data)
 
     def run_bwd(res, cts):
-        from repro.runtime.sharding import scan_compat
-
         unit_params, y1h, y1l, y2h, y2l, key_data = res
         # TwoSum's error channel has a zero jacobian, so the lo outputs
         # carry no gradient into the stage (dy1l/dy2l are dropped exactly
@@ -424,7 +420,7 @@ def reversible_stage(cfg, rcfg, unit, si, resolved, unit_params,
                 y2h, y2l = _rev_anchor(rcfg, x2h), _rev_anchor(rcfg, x2l)
             return (y1h, y1l, y2h, y2l, dy1, dy2), dparams
 
-        (_, _, _, _, dx1, dx2), dups = scan_compat(
+        (_, _, _, _, dx1, dx2), dups = jax.lax.scan(
             back, (y1h, y1l, y2h, y2l, dy1, dy2), (unit_params, key_data),
             reverse=True)
         dkd = jax.tree.map(

@@ -187,11 +187,10 @@ def chunked_cross_entropy(h, w_head, labels, mask, chunk: int,
                 idx + 1, stats_acc), None
 
     from repro.core.linear import STATS_LEN
-    from repro.runtime.sharding import scan_compat
 
     init = (jnp.float32(0), jnp.float32(0), jnp.int32(0),
             jnp.zeros((STATS_LEN,), jnp.float32))
-    (tot, cnt, _, stats), _ = scan_compat(body, init, (hc, lc, mc))
+    (tot, cnt, _, stats), _ = jax.lax.scan(body, init, (hc, lc, mc))
     loss = tot / jnp.maximum(cnt, 1.0)
     if site is not None:
         return loss, stats

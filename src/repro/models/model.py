@@ -246,11 +246,7 @@ def forward(cfg, rcfg, plan, params, batch, key, *, telemetry: dict | None = Non
 
         keys = jax.random.split(stage_key, rep)
         if rep > 1:
-            # scan_compat: unrolled inside the shard_map executor's body
-            # (grad-of-scan is miscompiled under partial-auto SPMD).
-            from repro.runtime.sharding import scan_compat
-
-            (x, aux, tele), _ = scan_compat(body, (x, aux, tele), (unit_params, keys))
+            (x, aux, tele), _ = jax.lax.scan(body, (x, aux, tele), (unit_params, keys))
         else:
             sliced = jax.tree.map(lambda t: t[0], unit_params)
             (x, aux, tele), _ = body((x, aux, tele), (sliced, keys[0]))

@@ -351,10 +351,10 @@ def shard_map_ctx(mesh: Mesh, manual_axes: tuple):
     """Mark that model code is being traced inside a ``shard_map`` body whose
     ``manual_axes`` are manually sharded (the rest are GSPMD-auto).
 
-    ``maybe_constrain`` then emits explicit NamedSharding constraints against
-    ``mesh`` with the manual axes dropped from the logical rules — inside a
-    partial-auto shard_map a constraint may only name auto axes, and the
-    manual (data) axes are already physically split by the shard_map itself."""
+    ``maybe_constrain`` then emits explicit NamedSharding constraints with
+    the manual axes dropped from the logical rules — inside a partial-auto
+    shard_map a constraint may only name auto axes, and the manual (data)
+    axes are already physically split by the shard_map itself."""
     prev = getattr(_SM_CTX, "val", None)
     _SM_CTX.val = (mesh, frozenset(manual_axes))
     try:
@@ -367,58 +367,10 @@ def _shard_map_context():
     return getattr(_SM_CTX, "val", None)
 
 
-def scan_compat(body, carry, xs, *, length=None, reverse=False):
-    """``jax.lax.scan`` — unrolled to a Python loop when tracing inside a
-    shard_map body (``shard_map_ctx`` active).
-
-    XLA's SPMD partitioner (this jaxlib line) fails a
-    ``sharding.IsManualSubgroup()`` CHECK when differentiating a scan under
-    partial-auto manual sharding (hlo_sharding_util.cc); unrolling trades
-    HLO size linear in the scan length for a correct lowering. Outside a
-    shard_map body this IS ``lax.scan``, bit for bit.
-
-    ``reverse=True`` matches ``lax.scan``'s contract: iterate xs from the
-    last slice to the first, with ys still stacked in input (index) order —
-    the reversible-block backward (models/blocks.reversible_stage) walks
-    layers top-down this way.
-    """
-    if _shard_map_context() is None:
-        return jax.lax.scan(body, carry, xs, length=length, reverse=reverse)
-    n = length if xs is None else jax.tree.leaves(xs)[0].shape[0]
-    ys = [None] * n
-    order = range(n - 1, -1, -1) if reverse else range(n)
-    for i in order:
-        xi = None if xs is None else jax.tree.map(lambda t: t[i], xs)
-        carry, ys[i] = body(carry, xi)
-    if not ys or ys[0] is None:
-        return carry, None
-    import jax.numpy as jnp
-
-    return carry, jax.tree.map(lambda *ts: jnp.stack(ts), *ys)
-
-
 def current_mesh_axis_names() -> tuple[str, ...] | None:
-    """Axis names of the mesh currently in context, or None.
-
-    Version-portable: newer JAX exposes ``jax.sharding.get_abstract_mesh``;
-    older releases only track the physical mesh set by the ``with mesh:``
-    context manager.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        am = get_abstract()
-        if am is None or am.empty:
-            return None
-        return tuple(am.axis_names)
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if pm is None or pm.empty:
-        return None
-    return tuple(pm.axis_names)
+    """Axis names of the mesh set by ``jax.set_mesh``, or None."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else tuple(am.axis_names)
 
 
 def _rules_pspec(logical: tuple, axes: set) -> PS:
@@ -438,12 +390,16 @@ def maybe_constrain(x, logical: tuple):
     outside a mesh context — keeps model code mesh-agnostic for CPU tests).
 
     Inside a ``shard_map_ctx`` (the shard_map executor's body), the manual
-    axes are dropped from the rules and an explicit NamedSharding against
-    the executor's mesh is emitted for the remaining (auto/TP) axes."""
+    axes are dropped from the rules and an explicit NamedSharding for the
+    remaining (auto/TP) axes is emitted against the body's abstract mesh,
+    whose axis types mark the data axes manual — a sharding on the
+    all-auto concrete mesh is refused inside a scan body."""
     sm = _shard_map_context()
     if sm is not None:
         mesh, manual = sm
         axes = set(mesh.axis_names) - manual
+        if not axes:
+            return x   # fully manual body: nothing left to constrain
         ps = _rules_pspec(logical, axes)
         # drop entries the dimension cannot divide (MQA kv heads, odd vocab)
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -458,7 +414,8 @@ def maybe_constrain(x, logical: tuple):
         # An all-None spec is still meaningful here: it pins the value
         # REPLICATED over the auto (TP) axes — that is exactly the
         # block-boundary residual anchor — so it is emitted, not skipped.
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, PS(*spec)))
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(jax.sharding.get_abstract_mesh(), PS(*spec)))
     names = current_mesh_axis_names()
     if names is None:
         return x

@@ -6,10 +6,11 @@ compress, no handle on the collective schedule, and "data parallel" is just
 a layout hint. This module makes the data axis *manual*:
 
   * the mesh's data axes (``('pod','data')`` or ``('data',)``) are manually
-    sharded by ``jax.experimental.shard_map`` — each shard runs its own
+    sharded by ``jax.shard_map`` — each shard runs its own
     forward/backward (through the same Pallas flash-attention fwd+bwd and
     PAMM custom_vjp paths as the jit executor) on its slice of the batch;
-  * the model axes stay GSPMD-auto (``auto=frozenset({'model', ...})``), so
+  * the model axes stay GSPMD-auto (outside ``axis_names``) when they
+    have more than one device, so
     tensor parallelism over ``heads``/``ffn``/``vocab`` keeps lowering to
     the intended all-reduces inside each shard's replica group, steered by
     the ``maybe_constrain`` activation annotations at block boundaries
@@ -40,7 +41,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as PS
 
 from repro.core.plan import resolve_for_run
@@ -169,10 +169,16 @@ def make_shard_map_train_step(cfg, rcfg, *, total_steps: int = 10000, mesh,
     data_axes = sh.data_axis_names(mesh)
     ctx_axes = sh.context_axis_names(mesh)
     sync_axes = data_axes + ctx_axes
+    # Mosaic (Pallas TPU) kernels lower only where every mesh axis is
+    # manual, so size-1 axes join the manual set: with model=1 the whole
+    # body is manual and the flash/PAMM kernels compile for the chip.
+    # Tensor parallelism (model > 1) stays GSPMD-auto.
+    manual_axes = sync_axes + tuple(
+        a for a in mesh.axis_names
+        if a not in sync_axes and mesh.shape[a] == 1)
     dp = sh.dp_degree(mesh)
     cp = sh.cp_degree(mesh)
     n_shards = dp * cp
-    auto_axes = frozenset(a for a in mesh.axis_names if a not in sync_axes)
     dspec = sh.data_pspec(mesh)
     cspec = PS(ctx_axes[0]) if ctx_axes else PS()
     bspec = sh.batch_pspec(mesh)
@@ -219,7 +225,7 @@ def make_shard_map_train_step(cfg, rcfg, *, total_steps: int = 10000, mesh,
         # included). The step key likewise enters as raw uint32 key data:
         # a typed key array crossing the shard_map boundary trips GSPMD's
         # sharding validation for extended dtypes.
-        with sh.shard_map_ctx(mesh, sync_axes):
+        with sh.shard_map_ctx(mesh, manual_axes):
             shard = sid[0] * cp + cid[0]
             resolved = resolved_base
             if n_shards > 1:
@@ -265,11 +271,11 @@ def make_shard_map_train_step(cfg, rcfg, *, total_steps: int = 10000, mesh,
             loss = jax.lax.pmean(loss, sync_axes)
             return loss, metrics, grads, new_ef
 
-    grads_fn = shard_map(
-        shard_body, mesh,
+    grads_fn = jax.shard_map(
+        shard_body, mesh=mesh,
         in_specs=(dspec, cspec, PS(), PS(), efspec, bspec),
         out_specs=(PS(), PS(), PS(), efspec),
-        check_rep=False, auto=auto_axes,
+        axis_names=frozenset(manual_axes), check_vma=False,
     )
 
     seq_perm = None

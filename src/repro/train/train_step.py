@@ -92,12 +92,10 @@ def loss_and_grad(cfg, rcfg, resolved, params, batch, key):
             m_acc = jax.tree.map(lambda a, v: a + v / accum, m_acc, metrics_i)
             return (l_acc + loss_i / accum, g_acc, m_acc), None
 
-        from repro.runtime.sharding import scan_compat
-
         zero_g = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
         zero_m = {"nll": jnp.float32(0), "aux": jnp.float32(0),
                   "sites": resolved.zero_telemetry()}
-        (loss, grads32, metrics), _ = scan_compat(
+        (loss, grads32, metrics), _ = jax.lax.scan(
             body, (jnp.float32(0), zero_g, zero_m), (micro_batches, mkeys)
         )
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads32, params)

@@ -52,7 +52,6 @@ def test_collectives_counted_inside_loops():
         pytest.skip("no devices")
     # single-device mesh: use psum via shard_map to force an all-reduce
     from jax.sharding import PartitionSpec as PS
-    from jax.experimental.shard_map import shard_map
 
     from repro.launch.mesh import make_debug_mesh
 
@@ -60,7 +59,7 @@ def test_collectives_counted_inside_loops():
 
     def f(xs):
         def body(c, x):
-            y = shard_map(lambda t: jax.lax.psum(t, "data"), mesh=mesh,
+            y = jax.shard_map(lambda t: jax.lax.psum(t, "data"), mesh=mesh,
                           in_specs=PS("data"), out_specs=PS())(x)
             return c + jnp.sum(y), None
         out, _ = jax.lax.scan(body, jnp.float32(0), xs)

@@ -329,11 +329,13 @@ PARITY_ARCHS = [
 ]
 
 
+# int8 KV moves any one decode logit by less than this (the int8 bound of
+# test_compressed_decode_logits_within_tolerance below).
+INT8_LOGIT_TOL = 0.15
+
+
 def _parity_reqs(cfg, imgs, base):
-    # deterministic scenario pinned for exact int8 greedy parity: quant
-    # noise (~0.03 logits) can flip near-tie argmaxes of random-init smoke
-    # models, so the test fixes prompts/lengths where margins are decisive
-    # (a per-arch prompt base — random prompts would flake on tie-breaks)
+    # fixed prompts and lengths, with a per-arch prompt base
     return [Request(uid=i, tokens=list(range(base, base + 8 + i)),
                     max_new_tokens=8, sampling=SamplingParams(),
                     image_embeds=imgs[i] if cfg.vision_tokens else None)
@@ -355,9 +357,28 @@ def test_int8_engine_greedy_matches_fp_paged(arch, base):
     q8 = ServeEngine(cfg, RCFG, params, max_slots=2, max_len=48,
                      decode_block=4, cache_layout="paged", page_size=8,
                      cache_compress="int8")
-    out_q = q8.run(_parity_reqs(cfg, imgs, base))
-    for i in range(3):
-        assert out_q[i].tokens == out_b[i].tokens, f"request {i} diverged"
+    reqs = _parity_reqs(cfg, imgs, base)
+    out_q = q8.run(reqs)
+    # Greedy over int8 logits can only pick another token than fp greedy
+    # where the fp logits of the two tokens lie within 2 * INT8_LOGIT_TOL:
+    # the tokens agree up to the first such near-tie, which is checked
+    # against fp logits of the shared prefix (random-init smoke models
+    # have near-ties that int8 noise may flip).
+    for i, req in enumerate(reqs):
+        fp, q8_toks = out_b[i].tokens, out_q[i].tokens
+        assert len(fp) == len(q8_toks) == req.max_new_tokens
+        j = next((j for j, (a, b) in enumerate(zip(fp, q8_toks)) if a != b),
+                 None)
+        if j is None:
+            continue
+        batch = {"tokens": jnp.asarray([req.tokens + fp[:j]], jnp.int32)}
+        if cfg.vision_tokens:
+            batch["image_embeds"] = jnp.asarray(imgs[i])[None]
+        logits, _ = prefill(cfg, RCFG, params, batch, 48)
+        margin = float(logits[0, -1, fp[j]] - logits[0, -1, q8_toks[j]])
+        assert abs(margin) <= 2 * INT8_LOGIT_TOL, (
+            f"request {i} step {j}: int8 picked token {q8_toks[j]} over "
+            f"{fp[j]}, fp logit margin {margin}")
     _drained(q8)
 
 

@@ -279,7 +279,6 @@ def _dp_mesh(n):
 
 @multidevice
 def test_compressed_psum_is_mean_of_dequantized():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _dp_mesh(8)
@@ -291,8 +290,8 @@ def test_compressed_psum_is_mean_of_dequantized():
         out, new_err = compressed_psum(g[0], e[0], "data")
         return out[None], new_err[None]
 
-    f = shard_map(body, mesh, in_specs=(P("data"), P("data")),
-                  out_specs=(P("data"), P("data")), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+                      out_specs=(P("data"), P("data")), check_vma=False)
     out, new_err = jax.jit(f)(g, err)
     # every shard got the same mean; it equals the mean of the shard-wise
     # dequantized payloads, which is within quantization error of the true
@@ -312,7 +311,6 @@ def test_compressed_psum_is_mean_of_dequantized():
 def test_tree_compressed_psum_error_feedback_converges():
     """Summed over steps, EF compensates: the accumulated compressed means
     track the accumulated true means much closer than one step's error."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _dp_mesh(8)
@@ -331,8 +329,10 @@ def test_tree_compressed_psum_error_feedback_converges():
         return (jax.tree.map(lambda t: t[None], out),
                 jax.tree.map(lambda t: t[None], ne))
 
-    f = jax.jit(shard_map(body, mesh, in_specs=(P("data"), P("data")),
-                          out_specs=(P("data"), P("data")), check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=(P("data"), P("data")),
+                              out_specs=(P("data"), P("data")),
+                              check_vma=False))
     acc = {"a": 0.0, "b": 0.0}
     true = {"a": 0.0, "b": 0.0}
     for g in tree_steps:

@@ -226,7 +226,6 @@ def test_ring_parity_random_shapes(shape, seed):
     grad-of-sum, f32 rel < 1e-5) for random shapes and cp degrees, on the
     forced-8-device harness. Inputs ride the zigzag permutation exactly
     as the shard_map executor applies it."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.kernels.ring_attention import (
@@ -252,10 +251,10 @@ def test_ring_parity_random_shapes(shape, seed):
         return ring_attention(qs, ks_, vs, pos, axis_name="context", cp=cp,
                               causal=causal, window=window, bq=bq, bk=bk)
 
-    ring = jax.jit(shard_map(
+    ring = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "context"),) * 3 + (P("context"),),
-        out_specs=P(None, "context"), check_rep=False))
+        out_specs=P(None, "context"), check_vma=False))
 
     def f(q_, k_, v_):
         return jnp.sum(jnp.sin(ring(q_[:, perm], k_[:, perm], v_[:, perm], cid)))

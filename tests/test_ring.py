@@ -161,7 +161,6 @@ def test_jit_executor_rejects_context_mesh():
 # ring == single-device parity (forced 8 devices)
 # ---------------------------------------------------------------------------
 def _ring_vs_flash(cp, H, KV, window, use_kernel, L=64, B=2, dh=16):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:cp]), ("context",))
@@ -180,11 +179,11 @@ def _ring_vs_flash(cp, H, KV, window, use_kernel, L=64, B=2, dh=16):
                               causal=True, window=window,
                               use_kernel=use_kernel, bq=16, bk=16)
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "context"), P(None, "context"), P(None, "context"),
                   P("context")),
-        out_specs=P(None, "context"), check_rep=False))
+        out_specs=P(None, "context"), check_vma=False))
 
     out = np.asarray(f(q[:, perm], k[:, perm], v[:, perm], cid_g))[:, inv]
     ref = np.asarray(flash_attention(q, k, v, causal=True, window=window,

@@ -156,8 +156,10 @@ def test_percentile_nearest_rank():
 
 
 def test_sampling_deterministic_per_seed_and_index():
-    """The stream depends only on (seed, token index) — not slot or step."""
-    logits = jax.random.normal(jax.random.key(11), (2, 40))
+    """The stream depends only on (seed, token index) — not slot or step.
+    Both rows carry the same logits, so equal (seed, index) pairs must draw
+    the same token whichever row they sit in."""
+    logits = jnp.tile(jax.random.normal(jax.random.key(11), (1, 40)), (2, 1))
     t = jnp.full((2,), 0.9)
     k0 = jnp.zeros((2,), jnp.int32)
     a = sample_tokens(logits, jnp.array([7, 7]), jnp.array([3, 3]), t, k0)

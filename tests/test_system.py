@@ -88,3 +88,22 @@ def test_cli_train_entrypoint():
     )
     assert res.returncode == 0, res.stderr[-2000:]
     assert "done:" in res.stdout
+
+
+def test_compile_cache_follows_env_else_repo_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to a fixed directory in the checkout."""
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.REPO_ROOT / ".jax_cache")
+        assert (compile_cache.REPO_ROOT / "chip_smoke.py").is_file()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
