@@ -1,0 +1,6 @@
+"""Roofline share of the Pallas kernel ``flash_dkv`` over the traced train window."""
+from bench import common
+
+
+def read(ctx):
+    return common.kernel_roofline(ctx, "flash_dkv") if ctx["kind"] == "train" else None

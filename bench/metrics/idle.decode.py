@@ -1,0 +1,6 @@
+"""Share of the traced decode window in which no operation ran on the device."""
+from bench import common
+
+
+def read(ctx):
+    return common.idle(ctx)

@@ -1,0 +1,6 @@
+"""Roofline share of the Pallas kernel ``pamm_compress`` over the traced train window."""
+from bench import common
+
+
+def read(ctx):
+    return common.kernel_roofline(ctx, "pamm_compress")
