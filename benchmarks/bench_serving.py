@@ -163,13 +163,14 @@ def run(budget: str = "small"):
          f"tok_per_s={st['prefill_tok_s']:.1f}")
     emit("serving_engine_decode", st["decode_s"] * 1e6,
          f"tok_per_s={st['decode_tok_s']:.1f}")
-    emit("serving_p50_token_latency_us", st["p50_token_latency_ms"] * 1e3, "")
-    emit("serving_p95_token_latency_us", st["p95_token_latency_ms"] * 1e3, "")
+    emit("serving_queue_wait_p90_us", st["queue_wait_ms_p90"] * 1e3, "")
+    emit("serving_ttft_p50_us", st["ttft_ms_p50"] * 1e3, "")
+    emit("serving_ttft_p90_us", st["ttft_ms_p90"] * 1e3, "")
     note(f"[serving] {arch} B={B} prompt={lp} gen={gen}: fused "
          f"{tps_fused:.0f} tok/s vs per-token streaming {tps_stream:.0f} "
          f"(async {tps_async:.0f}) tok/s ({tps_fused / tps_stream:.1f}x); "
-         f"engine p50/p95 "
-         f"{st['p50_token_latency_ms']:.2f}/{st['p95_token_latency_ms']:.2f} ms")
+         f"engine ttft p50/p90 "
+         f"{st['ttft_ms_p50']:.2f}/{st['ttft_ms_p90']:.2f} ms")
 
 
 def run_paged_mixed(budget: str = "small"):

@@ -64,6 +64,12 @@ def _make_engine(cfg, rcfg, params, args, *, mesh=None, slots=None):
                        mesh=mesh)
 
 
+def _ms(seconds: float | None) -> str:
+    """Milliseconds for a per-request stamp; "-" where the request came
+    through a router, whose engines never queued it."""
+    return "-" if seconds is None else f"{1e3 * seconds:.1f} ms"
+
+
 def _serve_once(cfg, rcfg, params, args):
     mesh = None
     if args.mesh_data > 1:
@@ -153,7 +159,8 @@ def main(argv=None):
     for uid in sorted(results):
         r = results[uid]
         print(f"req {uid}: prompt={r.prompt_len} new={len(r.tokens)} "
-              f"finish={r.finish_reason} {r.decode_tok_s:.1f} tok/s "
+              f"finish={r.finish_reason} queued {_ms(r.queued_s)} "
+              f"ttft {_ms(r.ttft_s)} "
               f"sample={r.tokens[:8]}")
     if args.replicas > 1:
         print(f"router: {stats['replicas']} replicas"
@@ -167,8 +174,10 @@ def main(argv=None):
     else:
         print(f"prefill {stats['prefill_tok_s']:.1f} tok/s | "
               f"decode {stats['decode_tok_s']:.1f} tok/s | "
-              f"p50 {stats['p50_token_latency_ms']:.2f} ms | "
-              f"p95 {stats['p95_token_latency_ms']:.2f} ms | "
+              f"queue wait p50/p90 {stats['queue_wait_ms_p50']:.2f}/"
+              f"{stats['queue_wait_ms_p90']:.2f} ms | "
+              f"ttft p50/p90 {stats['ttft_ms_p50']:.2f}/"
+              f"{stats['ttft_ms_p90']:.2f} ms | "
               f"cache {stats['cache_slot_bytes'] / 1e6:.2f} MB/slot")
         layout = args.cache_layout + (
             f"+{args.cache_compress}" if args.cache_compress else "")

@@ -89,6 +89,7 @@ from repro.core import stats as stats_lib
 from repro.core.plan import cache_plan_from_spec
 from repro.models import decode_step, init_caches, prefill
 from repro.models.attention import PAGED_CACHE_TYPES, SVDPagedKVCache
+from repro.runtime.spans import span
 from repro.serve import cache as cache_lib
 from repro.serve import paging
 from repro.serve.sampling import SamplingParams, sample_tokens
@@ -126,21 +127,19 @@ class Request:
 
 @dataclasses.dataclass
 class RequestOutput:
+    """A finished request. ``queued_s`` runs from ``submit`` to the start of
+    its admission; ``ttft_s`` from ``submit`` to the return of the
+    ``step`` that admitted it, when its first token reaches the caller.
+    Both are None for a request admitted through ``admit_prefix`` (a
+    router's hand-off), which never passed this engine's queue."""
+
     uid: int
     prompt_len: int
     tokens: list[int]
     finish_reason: str          # "eos" | "length"
     prefill_s: float
-    decode_s: float             # wall time of the fused decode blocks this
-                                # request was active in (other requests'
-                                # admission prefills are excluded)
-
-    @property
-    def decode_tok_s(self) -> float:
-        """Decode-loop rate: the first token is sampled during prefill and
-        excluded from decode_s, so it is excluded from the count too."""
-        n = len(self.tokens) - 1
-        return n / self.decode_s if self.decode_s > 0 and n > 0 else 0.0
+    queued_s: float | None = None
+    ttft_s: float | None = None
 
 
 @dataclasses.dataclass
@@ -247,7 +246,6 @@ class GenerateOutput:
     emitted: np.ndarray       # (steps, B) int32; PAD_TOKEN where inactive
     was_active: np.ndarray    # (steps, B) bool
     steps: int
-    seconds: float
 
 
 def _state_prop(name: str):
@@ -473,9 +471,13 @@ class ServeEngine:
 
         self.queue: collections.deque[Request] = collections.deque()
         self._outputs: dict[int, list[int]] = {}
-        self._decode_acc: dict[int, float] = {}
         self._prefill_s: dict[int, float] = {}
         self._requests: dict[int, Request] = {}
+        # host stamps of requests that came through submit(), taken where
+        # the serve.submit / serve.admit / serve.step spans open and close
+        self._submit_t: dict[int, float] = {}
+        self._queued_s: dict[int, float] = {}
+        self._ttft_s: dict[int, float] = {}
 
         # aggregate stats
         self.prefill_tokens = 0
@@ -484,9 +486,11 @@ class ServeEngine:
         self.insert_time = 0.0
         self.decode_tokens = 0
         self.decode_time = 0.0
-        # seconds per decode step; bounded ring so a long-lived engine
-        # doesn't grow host memory one float per generated token
-        self.latency_samples: collections.deque[float] = collections.deque(
+        # per-request queue waits and times to first token (seconds);
+        # bounded rings so a long-lived engine's host memory stays flat
+        self.queue_wait_samples: collections.deque[float] = \
+            collections.deque(maxlen=65536)
+        self.ttft_samples: collections.deque[float] = collections.deque(
             maxlen=65536)
         # high-water marks across steps (a drained engine reads 0 reserved,
         # so peaks are what the paged-vs-dense comparison wants)
@@ -695,7 +699,8 @@ class ServeEngine:
             jnp.asarray(np.concatenate([ds.tok[:, None], drafts], axis=1)),
             jnp.asarray(ds.pos), jnp.asarray(ds.active))
         ds.caches = caches
-        greedy = np.array(greedy)                      # (B, k+1)
+        with span("serve.generate.wait"):
+            greedy = np.array(greedy)                  # (B, k+1)
         emitted = np.full((k + 1, B), PAD_TOKEN, np.int32)
         was_active = np.zeros((k + 1, B), bool)
         n_act = int(ds.active.sum())
@@ -727,15 +732,10 @@ class ServeEngine:
             ds.remaining[b] = rem
             ds.gen_idx[b] = gi
             ds.active[b] = alive
-        dt = time.perf_counter() - t0
-        n_emitted = int(was_active.sum())
-        n_steps_run = int(was_active.any(axis=1).sum())
-        self.decode_tokens += n_emitted
-        self.decode_time += dt
-        if n_steps_run:
-            self.latency_samples.extend([dt / n_steps_run] * n_steps_run)
+        self.decode_tokens += int(was_active.sum())
+        self.decode_time += time.perf_counter() - t0
         return ds, GenerateOutput(emitted=emitted, was_active=was_active,
-                                  steps=k + 1, seconds=dt)
+                                  steps=k + 1)
 
     # ------------------------------------------------------------------
     # stage API: prefill -> Prefix -> insert -> DecodeState -> generate
@@ -747,25 +747,26 @@ class ServeEngine:
         never re-touches the prompt."""
         lp = len(request.tokens)
         lb = self._bucket_len(lp)
-        toks = np.zeros((lb,), np.int32)
-        toks[:lp] = np.asarray(request.tokens, np.int32)
-        batch = {"tokens": jnp.asarray(toks)[None]}
-        if self.cfg.vision_tokens:
-            batch["image_embeds"] = jnp.asarray(
-                request.image_embeds, jnp.float32)[None]
-        t0 = time.perf_counter()
-        logits, pcaches = self._prefill_fn(params, batch,
-                                           jnp.asarray([lp], jnp.int32))
-        self.bucket_lens.add(lb)
-        tok0 = self._sample_first(
-            logits[0, -1, : self.cfg.vocab_size],
-            jnp.int32(request.sampling.seed),
-            jnp.float32(request.sampling.temperature),
-            jnp.int32(request.sampling.top_k),
-        )
-        tok0 = int(tok0)
-        jax.block_until_ready(pcaches)
-        dt = time.perf_counter() - t0
+        with span("serve.prefill", uid=request.uid):
+            toks = np.zeros((lb,), np.int32)
+            toks[:lp] = np.asarray(request.tokens, np.int32)
+            batch = {"tokens": jnp.asarray(toks)[None]}
+            if self.cfg.vision_tokens:
+                batch["image_embeds"] = jnp.asarray(
+                    request.image_embeds, jnp.float32)[None]
+            t0 = time.perf_counter()
+            logits, pcaches = self._prefill_fn(params, batch,
+                                               jnp.asarray([lp], jnp.int32))
+            self.bucket_lens.add(lb)
+            tok0 = self._sample_first(
+                logits[0, -1, : self.cfg.vocab_size],
+                jnp.int32(request.sampling.seed),
+                jnp.float32(request.sampling.temperature),
+                jnp.int32(request.sampling.top_k),
+            )
+            tok0 = int(tok0)
+            jax.block_until_ready(pcaches)
+            dt = time.perf_counter() - t0
         self.prefill_tokens += lp
         self.prefill_time += dt
         return Prefix(uid=request.uid, request=request, prompt_len=lp,
@@ -794,42 +795,43 @@ class ServeEngine:
                 "for it to finish or place into a free slot")
         req = prefix.request
         lp = prefix.prompt_len
-        t0 = time.perf_counter()
-        pcaches = prefix.caches
-        if not isinstance(jax.tree.leaves(pcaches)[0], jax.Array):
-            # host-transferred Prefix (router handoff): re-device the tree
-            pcaches = jax.tree.map(jnp.asarray, pcaches)
-        if self.allocators:
-            share = self._match_prefix(req.tokens)
-            rows, starts, srcs, dsts, flat_rows = self._alloc_rows(
-                req, slot, share)
-            decode_state.caches = self._write_slot_paged(
-                decode_state.caches, pcaches, rows, jnp.int32(slot),
-                jnp.int32(lp), starts)
-            m = 0 if share is None else share[1]
-            lo = (m // self.page_size) * self.page_size
-            if lo < m:
-                # the divergent page is fresh but its leading rows are
-                # still shared content: copy them from the owner's page
-                # BEFORE any decode write lands on this slot
-                decode_state.caches = self._cow_fn(
-                    decode_state.caches, srcs, dsts, jnp.int32(lo),
-                    jnp.int32(m))
-            if self.prefix_share:
-                if (self.speculative_k and share is not None
-                        and m == lp and share[0].stream):
-                    # full-prompt hit on a retired request: its recorded
-                    # continuation drafts this request's greedy stream
-                    self._draft_donor[req.uid] = list(share[0].stream)
-                    self._donor_ok[req.uid] = 0
-                self._register_prefix(req, flat_rows)
-        else:
-            decode_state.caches = self._write_slot(
-                decode_state.caches, pcaches, jnp.int32(slot),
-                jnp.int32(lp))
-        jax.block_until_ready(decode_state.caches)
-        self.insert_count += 1
-        self.insert_time += time.perf_counter() - t0
+        with span("serve.insert", uid=req.uid):
+            t0 = time.perf_counter()
+            pcaches = prefix.caches
+            if not isinstance(jax.tree.leaves(pcaches)[0], jax.Array):
+                # host-transferred Prefix (router handoff): re-device the tree
+                pcaches = jax.tree.map(jnp.asarray, pcaches)
+            if self.allocators:
+                share = self._match_prefix(req.tokens)
+                rows, starts, srcs, dsts, flat_rows = self._alloc_rows(
+                    req, slot, share)
+                decode_state.caches = self._write_slot_paged(
+                    decode_state.caches, pcaches, rows, jnp.int32(slot),
+                    jnp.int32(lp), starts)
+                m = 0 if share is None else share[1]
+                lo = (m // self.page_size) * self.page_size
+                if lo < m:
+                    # the divergent page is fresh but its leading rows are
+                    # still shared content: copy them from the owner's page
+                    # BEFORE any decode write lands on this slot
+                    decode_state.caches = self._cow_fn(
+                        decode_state.caches, srcs, dsts, jnp.int32(lo),
+                        jnp.int32(m))
+                if self.prefix_share:
+                    if (self.speculative_k and share is not None
+                            and m == lp and share[0].stream):
+                        # full-prompt hit on a retired request: its recorded
+                        # continuation drafts this request's greedy stream
+                        self._draft_donor[req.uid] = list(share[0].stream)
+                        self._donor_ok[req.uid] = 0
+                    self._register_prefix(req, flat_rows)
+            else:
+                decode_state.caches = self._write_slot(
+                    decode_state.caches, pcaches, jnp.int32(slot),
+                    jnp.int32(lp))
+            jax.block_until_ready(decode_state.caches)
+            self.insert_count += 1
+            self.insert_time += time.perf_counter() - t0
 
         decode_state.slot_uid[slot] = req.uid
         decode_state.tok[slot] = prefix.first_token
@@ -860,14 +862,17 @@ class ServeEngine:
             B = decode_state.active.shape[0]
             return decode_state, GenerateOutput(
                 emitted=np.full((0, B), PAD_TOKEN, np.int32),
-                was_active=np.zeros((0, B), bool), steps=0, seconds=0.0)
+                was_active=np.zeros((0, B), bool), steps=0)
+        n_active = int(decode_state.active.sum())
         if self.speculative_k and not np.any(
                 decode_state.temps[decode_state.active] > 0):
             # speculative verify is greedy-only (draft==argmax is the
             # acceptance rule); any sampling request in the batch drops
             # the whole block to the sequential loop so streams never mix
             # verify modes mid-request
-            return self._generate_spec(params, decode_state)
+            with span("serve.generate", steps=self.speculative_k + 1,
+                      active=n_active):
+                return self._generate_spec(params, decode_state)
         # Don't scan far past the longest remaining generation (inert
         # trailing iterations still run full decode steps over the batch),
         # but round tails up to a power of two: each distinct scan length
@@ -877,39 +882,35 @@ class ServeEngine:
         if cap < steps:
             steps = min(steps, 1 << (cap - 1).bit_length() if cap > 1 else 1)
         fn = self._get_decode(steps)
-        t0 = time.perf_counter()
-        carry, (emitted, was_active) = fn(
-            params, decode_state.caches,
-            jnp.asarray(decode_state.tok), jnp.asarray(decode_state.pos),
-            jnp.asarray(decode_state.active),
-            jnp.asarray(decode_state.remaining),
-            jnp.asarray(decode_state.gen_idx),
-            jnp.asarray(decode_state.seeds),
-            jnp.asarray(decode_state.temps),
-            jnp.asarray(decode_state.topks),
-            jnp.asarray(decode_state.eos_ids),
-        )
-        (decode_state.caches, tok, pos, active, remaining, gen_idx) = carry
-        emitted = np.asarray(emitted)          # (steps, B)
-        was_active = np.asarray(was_active)    # (steps, B)
-        dt = time.perf_counter() - t0
+        with span("serve.generate", steps=steps, active=n_active):
+            t0 = time.perf_counter()
+            carry, (emitted, was_active) = fn(
+                params, decode_state.caches,
+                jnp.asarray(decode_state.tok), jnp.asarray(decode_state.pos),
+                jnp.asarray(decode_state.active),
+                jnp.asarray(decode_state.remaining),
+                jnp.asarray(decode_state.gen_idx),
+                jnp.asarray(decode_state.seeds),
+                jnp.asarray(decode_state.temps),
+                jnp.asarray(decode_state.topks),
+                jnp.asarray(decode_state.eos_ids),
+            )
+            (decode_state.caches, tok, pos, active, remaining, gen_idx) = carry
+            with span("serve.generate.wait"):
+                emitted = np.asarray(emitted)      # (steps, B)
+            was_active = np.asarray(was_active)    # (steps, B)
+            self.decode_tokens += int(was_active.sum())
+            self.decode_time += time.perf_counter() - t0
 
-        n_emitted = int(was_active.sum())
-        n_steps_run = int(was_active.any(axis=1).sum())
-        self.decode_tokens += n_emitted
-        self.decode_time += dt
-        if n_steps_run:
-            self.latency_samples.extend([dt / n_steps_run] * n_steps_run)
-
-        # np.array (not asarray): device arrays view as read-only buffers
-        decode_state.tok = np.array(tok)
-        decode_state.pos = np.array(pos)
-        decode_state.remaining = np.array(remaining)
-        decode_state.gen_idx = np.array(gen_idx)
-        decode_state.active = np.array(active)
+            # np.array (not asarray): device arrays view as read-only buffers
+            decode_state.tok = np.array(tok)
+            decode_state.pos = np.array(pos)
+            decode_state.remaining = np.array(remaining)
+            decode_state.gen_idx = np.array(gen_idx)
+            decode_state.active = np.array(active)
         return decode_state, GenerateOutput(emitted=emitted,
                                             was_active=was_active,
-                                            steps=steps, seconds=dt)
+                                            steps=steps)
 
     # ------------------------------------------------------------------
     # scheduling
@@ -940,8 +941,10 @@ class ServeEngine:
                     f"pool_tokens or shrink prompt_len + max_new_tokens")
 
     def submit(self, req: Request) -> None:
-        self._validate_request(req)
-        self.queue.append(req)
+        with span("serve.submit", uid=req.uid):
+            self._validate_request(req)
+            self.queue.append(req)
+            self._submit_t[req.uid] = time.perf_counter()
 
     @property
     def has_work(self) -> bool:
@@ -1179,8 +1182,17 @@ class ServeEngine:
         return rows, starts, srcs, dsts, flat_rows
 
     def _admit(self, req: Request, slot: int) -> Optional[RequestOutput]:
-        """Orchestrated admission: prefill + insert + bookkeeping."""
-        return self.admit_prefix(self.prefill(self.params, req), slot)
+        """Orchestrated admission: prefill + insert + bookkeeping, and the
+        request's time in the queue."""
+        lp = len(req.tokens)
+        with span("serve.admit", uid=req.uid, prompt_len=lp,
+                  bucket=self._bucket_len(lp)):
+            t_sub = self._submit_t.get(req.uid)
+            if t_sub is not None:
+                wait = time.perf_counter() - t_sub
+                self._queued_s[req.uid] = wait
+                self.queue_wait_samples.append(wait)
+            return self.admit_prefix(self.prefill(self.params, req), slot)
 
     def admit_prefix(self, prefix: Prefix,
                      slot: int) -> Optional[RequestOutput]:
@@ -1192,7 +1204,6 @@ class ServeEngine:
         self._requests[req.uid] = req
         self._outputs[req.uid] = [prefix.first_token]
         self._prefill_s[req.uid] = prefix.prefill_s
-        self._decode_acc[req.uid] = 0.0
         if not self.active[slot]:
             return self._finish(slot)
         return None
@@ -1209,7 +1220,8 @@ class ServeEngine:
             tokens=toks,
             finish_reason=reason,
             prefill_s=self._prefill_s.pop(uid),
-            decode_s=self._decode_acc.pop(uid),
+            queued_s=self._queued_s.pop(uid, None),
+            ttft_s=self._ttft_s.pop(uid, None),
         )
         self.slot_uid[slot] = -1
         self.active[slot] = False
@@ -1240,48 +1252,58 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def step(self, *, decode_steps: int | None = None) -> list[RequestOutput]:
         """Admit what fits, then run one fused decode block. Returns the
-        requests that finished during this step."""
+        requests that finished during this step. A request admitted here
+        has its first token when the step returns: that is its ``ttft_s``."""
         finished: list[RequestOutput] = []
-        while self.queue:
-            slot = self.try_place(self.queue[0])
-            if slot is None:
-                # strict FIFO: when the head can't get a slot + pages,
-                # later (maybe smaller) requests wait too — admission
-                # order, and hence every token stream, stays deterministic
-                break
-            done = self._admit(self.queue.popleft(), slot)
-            if done is not None:
-                finished.append(done)
+        admitted: list[int] = []
+        with span("serve.step"):
+            while self.queue:
+                slot = self.try_place(self.queue[0])
+                if slot is None:
+                    # strict FIFO: when the head can't get a slot + pages,
+                    # later (maybe smaller) requests wait too — admission
+                    # order, and hence every token stream, stays
+                    # deterministic
+                    break
+                req = self.queue.popleft()
+                admitted.append(req.uid)
+                done = self._admit(req, slot)
+                if done is not None:
+                    finished.append(done)
 
-        self.peak_active = max(self.peak_active, int(self.active.sum()))
-        reserved, used, _, _ = self._cache_usage()
-        self.peak_reserved_bytes = max(self.peak_reserved_bytes, reserved)
-        self.peak_used_bytes = max(self.peak_used_bytes, used)
+            self.peak_active = max(self.peak_active, int(self.active.sum()))
+            reserved, used, _, _ = self._cache_usage()
+            self.peak_reserved_bytes = max(self.peak_reserved_bytes, reserved)
+            self.peak_used_bytes = max(self.peak_used_bytes, used)
 
-        if not self.active.any():
-            return finished
-
-        prev_active = self.active.copy()
-        self.decode_state, out = self.generate(
-            self.params, self.decode_state, steps=decode_steps)
-
-        # used peaks AFTER the decode block lands (positions advanced,
-        # slots not yet released) — the admission-time sample above only
-        # covers the prompt tokens
-        _, used, _, _ = self._cache_usage()
-        self.peak_used_bytes = max(self.peak_used_bytes, used)
-
-        for b in range(self.max_slots):
-            uid = int(self.slot_uid[b])
-            if uid < 0:
-                continue
-            if out.was_active[:, b].any():
-                self._decode_acc[uid] += out.seconds
-            for t in range(out.steps):
-                if out.was_active[t, b]:
-                    self._outputs[uid].append(int(out.emitted[t, b]))
-            if prev_active[b] and not self.active[b]:
-                finished.append(self._finish(b))
+            if self.active.any():
+                prev_active = self.active.copy()
+                self.decode_state, out = self.generate(
+                    self.params, self.decode_state, steps=decode_steps)
+                with span("serve.bookkeep"):
+                    # used peaks AFTER the decode block lands (positions
+                    # advanced, slots not yet released) — the admission-time
+                    # sample above only covers the prompt tokens
+                    _, used, _, _ = self._cache_usage()
+                    self.peak_used_bytes = max(self.peak_used_bytes, used)
+                    for b in range(self.max_slots):
+                        uid = int(self.slot_uid[b])
+                        if uid < 0:
+                            continue
+                        for t in range(out.steps):
+                            if out.was_active[t, b]:
+                                self._outputs[uid].append(int(out.emitted[t, b]))
+                        if prev_active[b] and not self.active[b]:
+                            finished.append(self._finish(b))
+            t_end = time.perf_counter()
+        for uid in admitted:
+            t_sub = self._submit_t.pop(uid, None)
+            if t_sub is not None:
+                self._ttft_s[uid] = t_end - t_sub
+                self.ttft_samples.append(t_end - t_sub)
+        for out in finished:
+            if out.uid in admitted:
+                out.ttft_s = self._ttft_s.pop(out.uid, None)
         return finished
 
     def run(self, requests: Sequence[Request]) -> dict[int, RequestOutput]:
@@ -1306,7 +1328,8 @@ class ServeEngine:
         self.insert_time = 0.0
         self.decode_tokens = 0
         self.decode_time = 0.0
-        self.latency_samples.clear()
+        self.queue_wait_samples.clear()
+        self.ttft_samples.clear()
         self.peak_active = 0
         self.peak_reserved_bytes = 0
         self.peak_used_bytes = 0
@@ -1356,11 +1379,8 @@ class ServeEngine:
             compression_x=self.kv_compression_x)
 
     def stats(self) -> dict:
-        lat = sorted(self.latency_samples)
-
-        def pct(p):
-            return _percentile(lat, p)
-
+        waits = sorted(self.queue_wait_samples)
+        ttfts = sorted(self.ttft_samples)
         out = {
             "prefill_tokens": self.prefill_tokens,
             "prefill_s": self.prefill_time,
@@ -1374,8 +1394,12 @@ class ServeEngine:
             "decode_s": self.decode_time,
             "decode_tok_s": (self.decode_tokens / self.decode_time
                              if self.decode_time else 0.0),
-            "p50_token_latency_ms": pct(0.50) * 1e3,
-            "p95_token_latency_ms": pct(0.95) * 1e3,
+            # per request, from submit(): to its admission, and to the
+            # return of the step that admitted it (its first token)
+            "queue_wait_ms_p50": _percentile(waits, 0.50) * 1e3,
+            "queue_wait_ms_p90": _percentile(waits, 0.90) * 1e3,
+            "ttft_ms_p50": _percentile(ttfts, 0.50) * 1e3,
+            "ttft_ms_p90": _percentile(ttfts, 0.90) * 1e3,
             "cache_slot_bytes": cache_lib.slot_bytes(self.caches, self.max_slots),
             "prefill_compiles": len(self.bucket_lens),
             "buckets_enabled": self.prefill_buckets,
