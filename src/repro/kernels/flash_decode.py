@@ -19,12 +19,13 @@ mode is far too slow per decode step for a per-token inner loop).
 Paged variant (:func:`flash_paged_decode`): the KV cache lives in a global
 page pool ``(n_pages, page_size, KV, dh)`` and each sequence owns a
 *block table* — logical kv block ``j`` of sequence ``b`` is physical page
-``block_table[b, j]`` (-1 = unmapped). The Pallas kernel gathers its kv
-tiles *through* the table: the block table is a scalar-prefetch operand,
-so the k/v BlockSpec index maps read the physical page id per grid step,
-and a tile whose table entry is -1 is skipped entirely (page-granular
-tile liveness; masking inside a live page still comes from ``page_pos``,
-the paged counterpart of ``slot_pos``). The jnp oracle gathers the pool
+``block_table[b, j]`` (-1 = unmapped). The Pallas kernel reads the pool
+where it lies, one whole page (every KV head) per grid step, *through*
+the table: the block table is a scalar-prefetch operand, so the k/v
+BlockSpec index maps read the physical page id per grid step, and a page
+whose table entry is -1 is skipped entirely (page-granular tile
+liveness; masking inside a live page still comes from ``page_pos``, the
+paged counterpart of ``slot_pos``). The jnp oracle gathers the pool
 through the same table and defers to :func:`flash_decode_ref`.
 
 Quantized variant (:func:`flash_paged_decode_quant`): the pool stores
@@ -236,25 +237,26 @@ def flash_decode_ref(q, k, v, q_pos, slot_pos, *, causal: bool = True,
     return out.reshape(B, Lq, H, dh).astype(q.dtype)
 
 
-def _row_positions(qpos_ref, b, lq: int, g: int):
-    """Absolute position of each score row ``l*g + h`` (query ``l`` of
-    sequence ``b``), read from the (B, lq) SMEM scalar table — a scalar
-    for the single-query step, an (lq*g, 1) column for speculative verify.
-    Built from scalars because a (1, lq) VMEM block would break the TPU
-    tiling rule and its transpose to a column does not lower."""
+def _row_positions(qpos_ref, b, lq: int, n: int):
+    """Absolute position of each score row ``l*n + i`` (query ``l`` of
+    sequence ``b``, ``n`` rows per query), read from the (B, lq) SMEM
+    scalar table — a scalar for the single-query step, an (lq*n, 1) column
+    for speculative verify. Built from scalars because a (1, lq) VMEM
+    block would break the TPU tiling rule and its transpose to a column
+    does not lower."""
     qpos = qpos_ref[b, lq - 1]
     if lq == 1:
         return qpos
-    row = jax.lax.broadcasted_iota(jnp.int32, (lq * g, 1), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (lq * n, 1), 0)
     for l in range(lq - 2, -1, -1):
-        qpos = jnp.where(row < (l + 1) * g, qpos_ref[b, l], qpos)
+        qpos = jnp.where(row < (l + 1) * n, qpos_ref[b, l], qpos)
     return qpos
 
 
-def _paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, ppos_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, nb: int, kv: int,
+def _paged_decode_kernel(bt_ref, fetch_ref, qpos_ref, q_ref, k_ref, v_ref,
+                         ppos_ref, o_ref, m_ref, l_ref, acc_ref, *, nb: int,
                          lq: int, causal: bool, window: int, scale: float):
-    bh = pl.program_id(0)
+    b = pl.program_id(0)
     jk = pl.program_id(1)
 
     @pl.when(jk == 0)
@@ -264,25 +266,29 @@ def _paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, ppos_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Tile liveness is page-granular: an unmapped block-table entry means
-    # the whole kv tile is dead, so its loads/FLOPs are skipped — the
-    # index map already clamped the page id, making the (ignored) block
-    # fetch safe.
-    page = bt_ref[bh // kv, jk]
-
-    @pl.when(page >= 0)
+    # the whole page is dead, so its FLOPs are skipped — and its index map
+    # repeated the previous step's page, so nothing was fetched for it.
+    @pl.when(bt_ref[b, jk] >= 0)
     def _tile():
-        q = q_ref[0].astype(jnp.float32)       # (lq*G, dhp)
-        k = k_ref[0, 0].astype(jnp.float32)    # (psp, dhp)
-        v = v_ref[0, 0].astype(jnp.float32)    # (psp, dhp)
+        _, ps, kv, dh = k_ref.shape
+        h = q_ref.shape[1] // lq
+        q = q_ref[0].astype(jnp.float32)                     # (lq*H, dh)
+        # the page's rows in pool order: row t*KV + j is token t, head j
+        k = k_ref[0].astype(jnp.float32).reshape(ps * kv, dh)
+        v = v_ref[0].astype(jnp.float32).reshape(ps * kv, dh)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                               # (lq*G, psp)
+        ) * scale                                            # (lq*H, ps*KV)
 
-        # per-row query position: row l*G + g is query l (speculative
-        # verify feeds lq > 1 drafted tokens at ascending positions)
-        qpos = _row_positions(qpos_ref, bh // kv, lq, q_ref.shape[1] // lq)
-        spos = ppos_ref[...]                    # (1, psp) absolute positions
-        mask = spos >= 0
+        # score row l*H + i is query l, head i, which reads kv head i // G;
+        # every other head's columns are masked out
+        shape = (lq * h, ps * kv)
+        row_head = jax.lax.broadcasted_iota(jnp.int32, shape, 0) % h
+        col_head = jax.lax.broadcasted_iota(jnp.int32, shape, 1) % kv
+        mask = row_head // (h // kv) == col_head
+        qpos = _row_positions(qpos_ref, b, lq, h)
+        spos = ppos_ref[...]                  # (1, ps*KV) position per row
+        mask = mask & (spos >= 0)
         if causal:
             mask = mask & (spos <= qpos)
         if window > 0:
@@ -291,7 +297,8 @@ def _paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, ppos_ref,
 
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        # a row whose heads are all masked in this page keeps p at 0
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = corr * acc_ref[...] + jax.lax.dot_general(
@@ -303,6 +310,17 @@ def _paged_decode_kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, ppos_ref,
     def _write():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
+
+
+def _fetch_pages(block_table):
+    """The page each grid step fetches: its own where mapped, else the
+    page fetched last in grid order (page 0 before any). A dead step then
+    names the block already in VMEM, and the pipeline issues no copy."""
+    flat = block_table.reshape(-1)
+    idx = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(flat >= 0, idx, -1))
+    fetch = jnp.where(last >= 0, flat[jnp.maximum(last, 0)], 0)
+    return fetch.reshape(block_table.shape)
 
 
 @functools.partial(
@@ -318,75 +336,60 @@ def flash_paged_decode_kernel(q, k_pages, v_pages, q_pos, block_table,
     (n_pages, page_size) int32 absolute-position-per-slot (-1 = empty).
     Returns (B, Lq, H, dh).
 
-    Lq > 1 is the speculative-verify path: the Lq drafted queries fold
-    into the kernel's row (sublane) dim next to the G grouped heads —
-    (B*KV, Lq*G, dhp) — so one grid walk over the pages scores every
-    draft at once, with per-row positions rebuilt from the (B, Lq) qpos
-    table in SMEM (:func:`_row_positions`). The grid and page gathers are
-    identical to the Lq=1 step; only the row count of the score tile
-    grows.
+    The pools go in as stored: a K or V block is one whole page
+    ``(1, page_size, KV, dh)``, whose two minor dims are the array's own,
+    so any page size, KV count or head width (the SVD pools' rank r) is a
+    legal block and the pool is never padded, transposed or copied. The
+    grid is (B, nb): one step per slot and logical block scores all the
+    slot's ``Lq*H`` query rows against the page's ``page_size*KV`` rows,
+    with each row's scores outside its own kv head masked out.
 
-    The kv tile size IS the page size, so pick page_size >= the dtype's
-    sublane granule (8 for f32, 16 for bf16) on real TPUs; smaller pages
-    are padded (pad rows masked via page_pos = -1).
+    Lq > 1 is the speculative-verify path: the Lq drafted queries fold
+    into the row dim — (B, Lq*H, dh) — so one walk over the pages scores
+    every draft at once, with per-row positions rebuilt from the (B, Lq)
+    qpos table in SMEM (:func:`_row_positions`).
     """
     B, Lq, H, dh = q.shape
     n_pages, ps, KV, _ = k_pages.shape
     nb = block_table.shape[1]
-    G = H // KV
     scale = dh ** -0.5 if scale is None else scale
-    pdh = (-dh) % 128
-    pps = (-ps) % 8
-    dhp, psp = dh + pdh, ps + pps
 
-    qr = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pdh)))
-    qr = qr.reshape(B, Lq, KV, G, dhp).transpose(0, 2, 1, 3, 4)
-    qr = qr.reshape(B * KV, Lq * G, dhp)
-    # kv head becomes the leading (grid-indexed) dim; page stays a whole
-    # block so the index map can pick it straight off the block table.
-    kt = jnp.pad(k_pages, ((0, 0), (0, pps), (0, 0), (0, pdh))
-                 ).transpose(2, 0, 1, 3)        # (KV, n_pages, psp, dhp)
-    vt = jnp.pad(v_pages, ((0, 0), (0, pps), (0, 0), (0, pdh))
-                 ).transpose(2, 0, 1, 3)
-    pposr = _page_pos_rows(page_pos, pps)
+    qr = q.reshape(B, Lq * H, dh)
+    # one position per pool row (token t, head j at row t*KV + j)
+    pposr = jnp.repeat(page_pos, KV, axis=1)[:, None]   # (n_pages, 1, ps*KV)
     qposr = jnp.broadcast_to(q_pos.reshape(B, -1), (B, Lq)).astype(jnp.int32)
     bt = block_table.astype(jnp.int32)
 
-    def page_of(bh, jk, bt_ref, *_):
-        return jnp.maximum(bt_ref[bh // KV, jk], 0)
+    def page_of(b, jk, bt_ref, fetch_ref, *_):
+        return fetch_ref[b, jk]
 
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, nb=nb, kv=KV, lq=Lq,
-                          causal=causal, window=window, scale=scale),
+        functools.partial(_paged_decode_kernel, nb=nb, lq=Lq, causal=causal,
+                          window=window, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B * KV, nb),
+            num_scalar_prefetch=3,
+            grid=(B, nb),
             in_specs=[
-                pl.BlockSpec((1, Lq * G, dhp),
-                             lambda bh, jk, *_: (bh, 0, 0)),
-                pl.BlockSpec((1, 1, psp, dhp),
-                             lambda bh, jk, *refs:
-                             (bh % KV, page_of(bh, jk, *refs), 0, 0)),
-                pl.BlockSpec((1, 1, psp, dhp),
-                             lambda bh, jk, *refs:
-                             (bh % KV, page_of(bh, jk, *refs), 0, 0)),
-                pl.BlockSpec((None, 1, psp),
-                             lambda bh, jk, *refs:
-                             (page_of(bh, jk, *refs), 0, 0)),
+                pl.BlockSpec((1, Lq * H, dh), lambda b, jk, *_: (b, 0, 0)),
+                pl.BlockSpec((1, ps, KV, dh), lambda b, jk, *refs:
+                             (page_of(b, jk, *refs), 0, 0, 0)),
+                pl.BlockSpec((1, ps, KV, dh), lambda b, jk, *refs:
+                             (page_of(b, jk, *refs), 0, 0, 0)),
+                pl.BlockSpec((None, 1, ps * KV), lambda b, jk, *refs:
+                             (page_of(b, jk, *refs), 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, Lq * G, dhp),
-                                   lambda bh, jk, *_: (bh, 0, 0)),
+            out_specs=pl.BlockSpec((1, Lq * H, dh),
+                                   lambda b, jk, *_: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((Lq * G, 1), jnp.float32),
-                pltpu.VMEM((Lq * G, 1), jnp.float32),
-                pltpu.VMEM((Lq * G, dhp), jnp.float32),
+                pltpu.VMEM((Lq * H, 1), jnp.float32),
+                pltpu.VMEM((Lq * H, 1), jnp.float32),
+                pltpu.VMEM((Lq * H, dh), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B * KV, Lq * G, dhp), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Lq * H, dh), q.dtype),
         interpret=interpret, name="paged_decode",
-    )(bt, qposr, qr, kt, vt, pposr)
-    out = out.reshape(B, KV, Lq, G, dhp)[..., :dh]
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, Lq, H, dh)
+    )(bt, _fetch_pages(bt), qposr, qr, k_pages, v_pages, pposr)
+    return out.reshape(B, Lq, H, dh)
 
 
 def _page_pos_rows(page_pos, pps: int):

@@ -313,6 +313,7 @@ def test_speculative_falls_back_when_batch_samples():
     (2, 64, 4, 2, 64, 16, 4),      # GQA, k=3 verify shape
     (1, 96, 4, 1, 32, 8, 5),       # MQA
     (2, 32, 8, 2, 80, 8, 2),       # non-128 head dim
+    (2, 256, 16, 8, 128, 128, 4),  # the serve cells' widths and pages
 ])
 def test_flash_paged_decode_multirow_vs_dense_ref(B, S, H, KV, dh, ps, Lq):
     """The Lq-folded paged kernel == dense reference for the short-Lq

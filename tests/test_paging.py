@@ -59,7 +59,7 @@ def _random_paging(k_dense, v_dense, spos, ps, n_pages, seed=0):
     free = list(rng.permutation(n_pages))
     for b in range(B):
         n_valid = int((spos[b] >= 0).sum())
-        for j in range(-(-max(n_valid, 1) // ps)):
+        for j in range(-(-n_valid // ps)):   # an empty slot maps no page
             p = free.pop()
             bt[b, j] = p
             k_pages[p] = k_dense[b, j * ps:(j + 1) * ps]
@@ -73,7 +73,10 @@ def _random_paging(k_dense, v_dense, spos, ps, n_pages, seed=0):
     (1, 96, 4, 1, 32, 8, 0),       # MQA
     (2, 32, 8, 2, 80, 8, 0),       # non-128 head dim
     (1, 16, 2, 2, 128, 8, 8),      # ring: window inside the logical size
-    (2, 48, 4, 2, 64, 12, 0),      # page size not a sublane multiple (pads)
+    (2, 48, 4, 2, 64, 12, 0),      # page size not a sublane multiple
+    (2, 256, 16, 8, 128, 128, 0),  # the serve cells' widths: G 2, 128-token pages
+    (6, 384, 16, 8, 128, 128, 0),  # ... with slots of 0 (unmapped), 1, ps, ps+1
+    (6, 49, 4, 1, 32, 7, 0),       # MQA at an odd page size, unpadded
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_paged_decode_vs_dense_ref(B, S, H, KV, dh, ps, window, dtype):
@@ -85,7 +88,10 @@ def test_flash_paged_decode_vs_dense_ref(B, S, H, KV, dh, ps, window, dtype):
     k = rng.standard_normal((B, S, KV, dh)).astype(np.float32)
     v = rng.standard_normal((B, S, KV, dh)).astype(np.float32)
     q = jnp.asarray(rng.standard_normal((B, 1, H, dh)), dtype)
-    n_valid = np.array([S - 3, S // 2][:B][:B] + [S] * max(0, B - 2))[:B]
+    # slots past the second hold 0 tokens (block-table row all -1), 1,
+    # page_size and page_size + 1 tokens, in turn
+    n_valid = np.array(([S - 3, S // 2] + [0, 1, ps, ps + 1] * B)[:B])
+    live = n_valid > 0
     spos = np.where(np.arange(S)[None] < n_valid[:, None],
                     np.arange(S)[None], -1).astype(np.int32)
     qpos = (n_valid - 1).astype(np.int32)
@@ -103,10 +109,13 @@ def test_flash_paged_decode_vs_dense_ref(B, S, H, KV, dh, ps, window, dtype):
                                        causal=True, window=window,
                                        interpret=True)
     tol = 5e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(o_ref, np.float32),
-                               np.asarray(o_dense, np.float32), atol=tol)
-    np.testing.assert_allclose(np.asarray(o_kern, np.float32),
-                               np.asarray(o_dense, np.float32), atol=tol)
+    np.testing.assert_allclose(np.asarray(o_ref, np.float32)[live],
+                               np.asarray(o_dense, np.float32)[live], atol=tol)
+    np.testing.assert_allclose(np.asarray(o_kern, np.float32)[live],
+                               np.asarray(o_dense, np.float32)[live], atol=tol)
+    # a slot with no mapped page attends to nothing: the kernel skips all
+    # its steps and returns zeros
+    assert np.all(np.asarray(o_kern, np.float32)[~live] == 0)
 
 
 def test_paged_insert_matches_dense_insert():

@@ -13,6 +13,7 @@ this module.
 """
 from __future__ import annotations
 
+import math
 import os
 import re
 
@@ -27,6 +28,9 @@ QKV_OUT = (H + 2 * KV) * DH
 PAMM_K = BATCH * SEQ // 512          # attn.qkv=pamm(r=1/512)
 SLOTS, PAGE, N_PAGES = 8, 16, 288
 BLOCKS = (512 + 32) // PAGE + 1
+# the serve-decode-heavy cell: 64 slots, a 48,000-token pool of 128-token
+# pages, max_len 2305
+SERVE = dict(slots=64, page=128, n_pages=375, blocks=19)
 
 
 @pytest.fixture(scope="module")
@@ -78,15 +82,15 @@ def _pamm_apply():
              ((BATCH * SEQ, QKV_OUT), jnp.float32)])
 
 
-def _paged(lq: int):
+def _paged(lq: int, slots=SLOTS, page=PAGE, n_pages=N_PAGES, blocks=BLOCKS):
     from repro.kernels.flash_decode import flash_paged_decode_kernel
 
     bf = jnp.bfloat16
     return (lambda q, k, v, qp, bt, pp: flash_paged_decode_kernel(
                 q, k, v, qp, bt, pp, interpret=False),
-            [((SLOTS, lq, H, DH), bf), ((N_PAGES, PAGE, KV, DH), bf),
-             ((N_PAGES, PAGE, KV, DH), bf), ((SLOTS, lq), jnp.int32),
-             ((SLOTS, BLOCKS), jnp.int32), ((N_PAGES, PAGE), jnp.int32)])
+            [((slots, lq, H, DH), bf), ((n_pages, page, KV, DH), bf),
+             ((n_pages, page, KV, DH), bf), ((slots, lq), jnp.int32),
+             ((slots, blocks), jnp.int32), ((n_pages, page), jnp.int32)])
 
 
 def _paged_quant(bits: int, ngr: int):
@@ -126,6 +130,7 @@ CASES = {
     "pamm_apply": ("pamm_apply", _pamm_apply),
     "paged_decode_fp": ("paged_decode", lambda: _paged(1)),
     "paged_decode_verify4": ("paged_decode", lambda: _paged(4)),
+    "paged_decode_serve": ("paged_decode", lambda: _paged(1, **SERVE)),
     "paged_decode_int8": ("paged_decode_quant", lambda: _paged_quant(8, 1)),
     "paged_decode_int4_group64": ("paged_decode_quant",
                                   lambda: _paged_quant(4, 2)),
@@ -133,12 +138,30 @@ CASES = {
 }
 
 
+def _lower(build, one_chip):
+    fn, shapes = build()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args)
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, case):
     kernel, build = CASES[case]
-    fn, shapes = build()
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    lowered = jax.jit(fn).lower(*args)
+    lowered = _lower(build, one_chip)
     assert kernel in re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
     compiled = lowered.compile()   # raises what the chip's compiler refuses
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_reads_pool_in_place(one_chip):
+    """At the serve cell's shapes the compiled decode program holds no
+    transpose or copy of a whole K or V pool: the kernel reads the pages
+    where they lie."""
+    pool = SERVE["n_pages"] * SERVE["page"] * KV * DH
+    hlo = _lower(lambda: _paged(1, **SERVE), one_chip).compile().as_text()
+    whole_pool = [
+        m.group(0)
+        for m in re.finditer(r"= \w+\[([\d,]*)\]\S* (transpose|copy)\(", hlo)
+        if math.prod(int(d) for d in m.group(1).split(",") if d) == pool]
+    assert not whole_pool, whole_pool
+
